@@ -168,7 +168,7 @@ class DeviceCepOperator:
         mesh, batch replicated, key-group masking per shard, deltas
         reassembled with one psum."""
         import jax.numpy as jnp
-        from flink_tpu.core.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from flink_tpu.core.keygroups import assign_to_key_group

@@ -3,12 +3,15 @@ and host spill store, bound via ctypes (SURVEY §2.10: the reference's
 Unsafe/Netty/RocksDB native surface, rebuilt for this runtime).
 
 The shared library compiles on first use (g++ -O2, ~1s) and is cached next
-to the sources; set FLINK_TPU_NATIVE_REBUILD=1 to force a rebuild.
+to the sources under a name keyed on a hash of the sources and the build
+command, so a binary that was not built from exactly these sources is never
+loaded (a copied tree may carry one with a newer mtime).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,7 +22,9 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "src")
-_SO = os.path.join(_DIR, "_flink_tpu_native.so")
+_SRCS = tuple(os.path.join(_SRC, f)
+              for f in ("ringbuf.cpp", "spillstore.cpp", "textparse.cpp"))
+_CXX = ("g++", "-O2", "-shared", "-fPIC", "-std=c++17")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -27,24 +32,24 @@ _lib: Optional[ctypes.CDLL] = None
 RECORD_BYTES = 20  # u64 key | i64 ts_ms | f32 value
 
 
+def _so_path() -> str:
+    h = hashlib.sha256(" ".join(_CXX).encode())
+    for s in _SRCS:
+        with open(s, "rb") as f:
+            h.update(f.read())
+    return os.path.join(_DIR, f"_flink_tpu_native.{h.hexdigest()[:16]}.so")
+
+
 def _build() -> str:
-    srcs = [os.path.join(_SRC, f)
-            for f in ("ringbuf.cpp", "spillstore.cpp", "textparse.cpp")]
-    newest_src = max(os.path.getmtime(s) for s in srcs)
-    if (
-        os.path.exists(_SO)
-        and os.path.getmtime(_SO) > newest_src
-        and not os.environ.get("FLINK_TPU_NATIVE_REBUILD")
-    ):
-        return _SO
-    tmp = _SO + ".tmp"
-    cmd = [
-        "g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-        "-o", tmp, *srcs, "-lrt",
-    ]
-    subprocess.run(cmd, check=True, capture_output=True)
-    os.replace(tmp, _SO)
-    return _SO
+    so = _so_path()
+    if os.path.exists(so):
+        return so
+    # per-process temp name: concurrent test workers may build at once
+    tmp = f"{so}.{os.getpid()}.tmp"
+    subprocess.run([*_CXX, "-o", tmp, *_SRCS, "-lrt"], check=True,
+                   capture_output=True)
+    os.replace(tmp, so)
+    return so
 
 
 def get_lib() -> ctypes.CDLL:
@@ -54,9 +59,9 @@ def get_lib() -> ctypes.CDLL:
             try:
                 lib = ctypes.CDLL(_build())
             except OSError:
-                # a stale/foreign-platform cached binary: force a rebuild
-                if os.path.exists(_SO):
-                    os.remove(_SO)
+                # a foreign-platform binary under the same source hash:
+                # rebuild it here
+                os.remove(_so_path())
                 lib = ctypes.CDLL(_build())
             u8p = ctypes.POINTER(ctypes.c_uint8)
             u64p = ctypes.POINTER(ctypes.c_uint64)

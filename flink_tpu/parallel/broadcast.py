@@ -22,7 +22,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from flink_tpu.core.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from flink_tpu.parallel.mesh import SHARD_AXIS, MeshContext

@@ -1122,7 +1122,7 @@ class DCNWindowRunner(_DCNRunnerBase):
     def _build_step(self):
         import jax
         import jax.numpy as jnp
-        from flink_tpu.core.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from flink_tpu.ops import window_kernels as wk
@@ -1328,7 +1328,7 @@ class DCNSessionRunner(_DCNRunnerBase):
     def _build_step(self):
         import jax
         import jax.numpy as jnp
-        from flink_tpu.core.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from flink_tpu.ops import session_windows as sw
@@ -1487,7 +1487,7 @@ class DCNRollingRunner(_DCNRunnerBase):
     def _build_step(self):
         import jax
         import jax.numpy as jnp
-        from flink_tpu.core.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from flink_tpu.ops import rolling
@@ -1617,7 +1617,7 @@ class DCNCEPRunner(_DCNRunnerBase):
     def _build_step(self):
         import jax
         import jax.numpy as jnp
-        from flink_tpu.core.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from flink_tpu.cep import device as cdev
@@ -1763,11 +1763,11 @@ def main(argv=None) -> int:
     ap.add_argument("--restore", action="store_true")
     a = ap.parse_args(argv)
 
-    plat = os.environ.get("JAX_PLATFORMS")
     import jax
 
-    if plat:
-        jax.config.update("jax_platforms", plat)
+    from flink_tpu.core.jaxenv import refuse_cpu_fallback
+
+    refuse_cpu_fallback()
     jax.distributed.initialize(
         coordinator_address=a.coordinator,
         num_processes=a.num_processes, process_id=a.process_id,

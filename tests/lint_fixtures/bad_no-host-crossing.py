@@ -2,7 +2,7 @@
 # lint-kernel-fixture
 #
 # BAD: a jax.debug.print left over from a debugging session inside the
-# scan body. It lowers to a debug_callback primitive — a device->host
+# scan body. It lowers to a debug_print primitive — a device->host
 # round trip on EVERY scan iteration, invisible to source-level
 # scanning once it hides in a helper, and exactly the serialization the
 # megastep exists to avoid.

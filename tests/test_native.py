@@ -174,3 +174,24 @@ def test_ring_source_end_to_end_window_job():
     env.execute("ring-ingest")
     t.join()
     assert sum(r.value for r in sink.results) == n_batches * B
+
+
+def test_library_is_named_by_a_hash_of_its_sources(tmp_path, monkeypatch):
+    """A binary that was not built from the committed sources is never
+    loaded: the name the loader builds and loads carries a hash of the
+    sources, so it moves with every source byte."""
+    import flink_tpu.native as native
+
+    loaded = native._so_path()
+    assert os.path.basename(loaded).startswith("_flink_tpu_native.")
+    assert native._build() == loaded
+    srcs = []
+    for s in native._SRCS:
+        dst = tmp_path / os.path.basename(s)
+        dst.write_bytes(open(s, "rb").read())
+        srcs.append(str(dst))
+    monkeypatch.setattr(native, "_SRCS", tuple(srcs))
+    assert native._so_path() == loaded
+    with open(srcs[0], "ab") as f:
+        f.write(b"\n// edited\n")
+    assert native._so_path() != loaded

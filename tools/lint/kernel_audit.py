@@ -62,7 +62,8 @@ OP_GROUPS = ("sort", "scatter", "gather", "while_scan", "cond")
 # primitives that cross the device/host boundary from inside a traced
 # program — any of these inside a kernel serializes the step pipeline
 HOST_CROSSING_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback",
     "infeed", "outfeed", "host_local_array_to_global_array",
     "device_put",
 })
@@ -89,25 +90,25 @@ def _op_group(prim_name: str) -> Optional[str]:
 
 def _subjaxprs(value):
     """Every ClosedJaxpr/Jaxpr reachable from one eqn.params value."""
-    import jax
+    from jax.extend import core as jex_core
 
     vals = value if isinstance(value, (list, tuple)) else (value,)
     for v in vals:
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jex_core.Jaxpr):
             yield v
 
 
 def iter_eqns(jaxpr, path: Tuple[str, ...] = ()):
     """Depth-first (path, eqn) over a jaxpr and everything it closes
-    over — scan/cond/while/pjit bodies included. ``path`` is the chain
+    over — scan/cond/while/jit bodies included. ``path`` is the chain
     of enclosing control primitives, so a rule can say "debug_callback
     inside scan" instead of just "somewhere"."""
     for eqn in jaxpr.eqns:
         yield path, eqn
         name = eqn.primitive.name
-        sub_path = path if name == "pjit" else path + (name,)
+        sub_path = path if name == "jit" else path + (name,)
         for v in eqn.params.values():
             for sub in _subjaxprs(v):
                 yield from iter_eqns(sub, sub_path)
@@ -116,11 +117,9 @@ def iter_eqns(jaxpr, path: Tuple[str, ...] = ()):
 def _aval_str(x) -> str:
     import jax
 
-    if isinstance(x, jax.ShapeDtypeStruct):
-        return jax.core.ShapedArray(x.shape, x.dtype).str_short()
     aval = getattr(x, "aval", None)
     if aval is None:
-        aval = jax.core.get_aval(x)
+        aval = jax.typeof(x)
     return aval.str_short()
 
 
@@ -175,7 +174,7 @@ class _Entry:
 def _trace_entry(e: _Entry) -> FamilyTrace:
     import jax
 
-    ctx = (jax.experimental.enable_x64() if e.x64
+    ctx = (jax.enable_x64(True) if e.x64
            else contextlib.nullcontext())
     with ctx, warnings.catch_warnings():
         warnings.simplefilter("ignore")

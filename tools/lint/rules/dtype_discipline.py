@@ -5,7 +5,7 @@ The state planes are f32/int32/uint32 by design — the TPU has no f64
 ALU (it emulates at >10x cost) and every widened plane doubles HBM
 traffic on the bandwidth-bound sweep. The classic leak: a Python float
 literal or an ``np.float64`` scalar folding into a traced op under
-``jax.experimental.enable_x64``, silently promoting a whole accumulator
+``jax.enable_x64``, silently promoting a whole accumulator
 plane. With x64 DISABLED the leak self-heals (JAX demotes), so unit
 tests never see it; this rule traces the canonical families and flags
 any equation whose output materializes float64/int64/uint64/complex128

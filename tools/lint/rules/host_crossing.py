@@ -4,7 +4,7 @@ inside a traced kernel.
 The AST hot-path-sync rule catches the constructs an author WRITES
 (``block_until_ready``, ``.item()``, ``np.asarray``); this is its
 compiled-program complement: ``jax.debug.print`` left over from a
-debugging session lowers to a ``debug_callback`` primitive INSIDE the
+debugging session lowers to a ``debug_print`` primitive INSIDE the
 megastep scan body, ``pure_callback``/``io_callback`` smuggle arbitrary
 host round trips into the graph, and a traced ``device_put`` is an
 implicit transfer — all invisible to source-level scanning once they
@@ -44,7 +44,7 @@ class HostCrossingRule(Rule):
                     f"kernel family {name!r}: host-crossing primitive "
                     f"{prim!r} {where} — every execution pays a device->"
                     f"host round trip (a leftover jax.debug.print lowers "
-                    f"to debug_callback; remove it or move the readback "
+                    f"to debug_print; remove it or move the readback "
                     f"to the lagged monitoring channel)",
                     tr.builder or "<family>",
                 ))
