@@ -537,319 +537,6 @@ def run_ingest_pipeline(total_events: int, cpu: bool):
             detail["prefetch_on"]["eps"])
 
 
-# ---------------------------------------------- observability overhead
-def run_observability_overhead(total_events: int, cpu: bool):
-    """Observability-overhead config (ISSUE 2): the same keyed windowed
-    sum run with span tracing off / sampled (every 64th cycle) / every
-    step, so the "negligible overhead" claim is measured, not asserted.
-    The always-on telemetry (kg_fill scatter + sampled monitoring fetch)
-    is present in every mode — the off row IS the shipping default.
-
-    subject = sampled-tracing eps, baseline = tracing-off eps (the ratio
-    is the sampled overhead; the every-step row rides the detail line).
-    """
-    from flink_tpu import StreamExecutionEnvironment
-    from flink_tpu.core.config import Configuration
-    from flink_tpu.core.time import TimeCharacteristic
-    from flink_tpu.runtime.sinks import CountingSink
-    from flink_tpu.runtime.sources import GeneratorSource
-
-    n_keys = 10_000
-
-    def gen(offset, n):
-        idx = np.arange(offset, offset + n)
-        cols = {
-            "key": (idx * 48271) % n_keys,
-            "value": np.ones(n, np.float32),
-        }
-        return cols, (idx // 4096) * 1000
-
-    def run(mode):
-        cfg = Configuration()
-        if mode != "off":
-            cfg.set("observability.tracing", True)
-            cfg.set("observability.trace-sample-every",
-                    64 if mode == "sampled" else 1)
-        env = StreamExecutionEnvironment(cfg)
-        env.set_parallelism(1)
-        env.set_max_parallelism(128)
-        env.set_stream_time_characteristic(TimeCharacteristic.EventTime)
-        env.set_state_capacity(1 << 15)
-        env.batch_size = 32768
-        sink = CountingSink()
-        t0 = time.perf_counter()
-        (
-            env.add_source(GeneratorSource(gen, total=total_events))
-            .key_by(lambda c: c["key"])
-            .time_window(10_000)
-            .sum(lambda c: c["value"])
-            .add_sink(sink)
-        )
-        env.execute(f"obs-bench-{mode}")
-        dt = time.perf_counter() - t0
-        assert sink.count > 0
-        tracer = env._span_tracer
-        return {
-            "eps": round(total_events / dt),
-            "spans": len(tracer) if tracer is not None else 0,
-            "spans_dropped": tracer.dropped if tracer is not None else 0,
-        }
-
-    detail = {m: run(m) for m in ("off", "sampled", "every_step")}
-    detail["resident_drain_stats"] = _resident_drain_stats_rows()
-    detail["chained_drain_stats"] = _chained_drain_stats_rows()
-    print(json.dumps(
-        {"config": "observability_overhead", "detail": detail}),
-        flush=True)
-    return detail["sampled"]["eps"], detail["off"]["eps"]
-
-
-def _resident_drain_stats_rows():
-    """Round-14 rows: the drain-interior flight recorder measured at the
-    PR 12 matched dims (B/C/ring/slide/D of ``run_resident_loop``, full
-    ring drains, lagged fire consumption). Three modes:
-
-    * ``off`` — ``drain_stats=False``: the kernel compiles WITHOUT the
-      telemetry payload (the trace-tier ledger pins this byte-identical
-      to pre-PR), so this row is the shipping default;
-    * ``sampled`` — payload compiled in, host fetches every 8th drain
-      (the ``observability.drain-stats-every`` default);
-    * ``every_drain`` — payload fetched with every fire batch.
-
-    The sampled-vs-off ratio is the acceptance criterion (<= 2%
-    events/s): the payload is element ops and tiny reductions over
-    fields the fused body already materialized, and the fetch rides the
-    existing lagged device_get, so the steady-state cost must stay in
-    the noise."""
-    from collections import deque as _dq
-
-    import jax
-    import jax.numpy as jnp
-
-    from flink_tpu.ops import window_kernels as wk
-    from flink_tpu.parallel.mesh import MeshContext
-    from flink_tpu.runtime.step import (
-        WindowStageSpec,
-        build_window_resident_drain,
-        init_sharded_state,
-    )
-
-    n_dev = len(jax.devices())
-    ctx = MeshContext.create(n_dev, 128)
-    B, C, RING, SLIDE = DEVICE_CEILING_BATCH, 4096, 9, 1000
-    BPP, D = 4, 32
-    n_groups = 6
-    n_batches = n_groups * D
-    spec = WindowStageSpec(
-        win=wk.WindowSpec(SLIDE, SLIDE, ring=RING, fires_per_step=4),
-        red=wk.ReduceSpec("sum", jnp.float32),
-        capacity_per_shard=C, layout="direct", precombine=False,
-    )
-
-    rng = np.random.default_rng(11)
-    batches, wms = [], []
-    for j in range(n_batches):
-        p = j // BPP
-        n_hot = B // 2
-        lo = np.concatenate([
-            rng.integers(0, C - 1, B - n_hot),
-            rng.integers(0, 64, n_hot),
-        ]).astype(np.uint32)
-        rng.shuffle(lo)
-        ts = np.full(B, p * SLIDE + SLIDE // 2, np.int32)
-        batches.append(tuple(jax.device_put(a) for a in (
-            np.zeros(B, np.uint32), lo, ts,
-            np.ones(B, np.float32), np.ones(B, bool),
-        )))
-        wms.append(np.int32(p * SLIDE - 1))
-
-    def measure(drain_stats, fetch_every):
-        step = build_window_resident_drain(
-            ctx, spec, D, reduced=True, drain_stats=drain_stats
-        )
-
-        def run_once():
-            state = init_sharded_state(ctx, spec)
-            t0 = time.perf_counter()
-            handles = _dq()
-            mon = None
-            for g in range(n_groups):
-                sel = range(g * D, (g + 1) * D)
-                flat = [a for i in sel for a in batches[i]]
-                wmv = np.tile(
-                    np.asarray([wms[i] for i in sel], np.int32),
-                    (n_dev, 1),
-                )
-                res = step(state, *flat, wmv, np.int32(D))
-                state, mon, fires = res[:3]
-                ds = (res[3] if drain_stats
-                      and (g + 1) % fetch_every == 0 else None)
-                handles.append((fires, ds))
-                if len(handles) > 1:
-                    cf, ds_h = handles.popleft()
-                    payload = (cf.counts, cf.lane_valid,
-                               cf.window_end_ticks, cf.value_sums)
-                    jax.device_get(
-                        payload + (ds_h,) if ds_h is not None
-                        else payload
-                    )
-            while handles:
-                cf, ds_h = handles.popleft()
-                payload = (cf.counts, cf.lane_valid,
-                           cf.window_end_ticks, cf.value_sums)
-                jax.device_get(
-                    payload + (ds_h,) if ds_h is not None else payload
-                )
-            jax.block_until_ready(mon[1])
-            return time.perf_counter() - t0
-
-        run_once()                               # compile + settle
-        dt = min(run_once() for _ in range(3))
-        return round(B * n_batches / dt)
-
-    rows = {
-        "off": measure(False, 0),
-        "sampled": measure(True, 8),
-        "every_drain": measure(True, 1),
-        "B": B, "C": C, "ring_depth": D, "n_batches": n_batches,
-        "fetch_every_sampled": 8,
-    }
-    rows["sampled_over_off"] = round(
-        rows["sampled"] / max(rows["off"], 1), 4
-    )
-    rows["criterion"] = "sampled >= 0.98x off (<= 2% overhead)"
-    return rows
-
-
-def _chained_drain_stats_rows():
-    """Round-17 rows: the STAGE-AWARE flight recorder measured inside
-    the 2-stage chained drain at the round-16 matched dims (B=512 /
-    C=4096 / ring depth D=32, firing rollup stream). Three modes,
-    mirroring ``_resident_drain_stats_rows``:
-
-    * ``off`` — ``drain_stats=False``: the chained kernel compiles
-      WITHOUT the telemetry payload (op_budget_pre_stage_stats.json
-      pins this byte-identical to pre-PR);
-    * ``sampled`` — stage-0 per-slot payload + per-downstream-stage
-      records compiled in, host fetches every 8th drain;
-    * ``every_drain`` — both payload planes fetched with every drain.
-
-    The sampled-vs-off ratio is the acceptance criterion (<= 2%
-    events/s): the stage tail's record is six scalar reductions over
-    planes the edge pack already materialized, riding the same lagged
-    fetch as the stage-0 block."""
-    from collections import deque as _dq
-
-    import jax
-    import jax.numpy as jnp
-
-    from flink_tpu.ops import window_kernels as wk
-    from flink_tpu.parallel.mesh import MeshContext
-    from flink_tpu.runtime.step import (
-        WindowStageSpec,
-        build_window_chained_drain,
-        init_sharded_state,
-    )
-
-    n_dev = len(jax.devices())
-    ctx = MeshContext.create(n_dev, 128)
-    B, C, RING, SLIDE = DEVICE_CEILING_BATCH, 4096, 9, 1000
-    BPP, D = 4, 32
-    ROLLUP, KEYSPACE, EX_LANES = 4, 256, 2048
-    n_groups = 6
-    n_batches = n_groups * D
-    spec1 = WindowStageSpec(
-        win=wk.WindowSpec(SLIDE, SLIDE, ring=RING, fires_per_step=4),
-        red=wk.ReduceSpec("sum", jnp.float32),
-        capacity_per_shard=C, layout="direct", precombine=False,
-    )
-    s2 = ROLLUP * SLIDE
-    slack = (D * spec1.win.fires_per_step * SLIDE) // s2 + 2
-    spec2 = WindowStageSpec(
-        win=wk.WindowSpec(s2, s2, ring=max(8, 2 + slack, 4),
-                          fires_per_step=4),
-        red=wk.ReduceSpec("sum", jnp.float32),
-        capacity_per_shard=C, layout="direct", precombine=False,
-    )
-
-    rng = np.random.default_rng(11)
-    batches, wms = [], []
-    for j in range(n_batches):
-        p = j // BPP
-        n_hot = B // 2
-        lo = np.concatenate([
-            rng.integers(0, KEYSPACE, B - n_hot),
-            rng.integers(0, 64, n_hot),
-        ]).astype(np.uint32)
-        rng.shuffle(lo)
-        ts = np.full(B, p * SLIDE + SLIDE // 2, np.int32)
-        batches.append(tuple(jax.device_put(a) for a in (
-            np.zeros(B, np.uint32), lo, ts,
-            np.ones(B, np.float32), np.ones(B, bool),
-        )))
-        wms.append(np.int32(p * SLIDE - 1))
-
-    def measure(drain_stats, fetch_every):
-        step = build_window_chained_drain(
-            ctx, (spec1, spec2), D, exchange_lanes=EX_LANES,
-            drain_stats=drain_stats,
-        )
-
-        def run_once():
-            state = (init_sharded_state(ctx, spec1),
-                     init_sharded_state(ctx, spec2))
-            t0 = time.perf_counter()
-            handles = _dq()
-            mon = None
-            for g in range(n_groups):
-                sel = range(g * D, (g + 1) * D)
-                flat = [a for i in sel for a in batches[i]]
-                wmv = np.tile(
-                    np.asarray([wms[i] for i in sel], np.int32),
-                    (n_dev, 1),
-                )
-                res = step(state, *flat, wmv, np.int32(D))
-                state, mon, fires = res[:3]
-                ds = (res[3] if drain_stats
-                      and (g + 1) % fetch_every == 0 else None)
-                handles.append((fires, ds))
-                if len(handles) > 1:
-                    cf, ds_h = handles.popleft()
-                    payload = (cf.counts, cf.lane_valid,
-                               cf.window_end_ticks, cf.value_sums)
-                    jax.device_get(
-                        payload + (ds_h,) if ds_h is not None
-                        else payload
-                    )
-            while handles:
-                cf, ds_h = handles.popleft()
-                payload = (cf.counts, cf.lane_valid,
-                           cf.window_end_ticks, cf.value_sums)
-                jax.device_get(
-                    payload + (ds_h,) if ds_h is not None else payload
-                )
-            jax.block_until_ready(mon[1])
-            return time.perf_counter() - t0
-
-        run_once()                               # compile + settle
-        dt = min(run_once() for _ in range(3))
-        return round(B * n_batches / dt)
-
-    rows = {
-        "off": measure(False, 0),
-        "sampled": measure(True, 8),
-        "every_drain": measure(True, 1),
-        "B": B, "C": C, "ring_depth": D, "n_batches": n_batches,
-        "n_stages": 2, "exchange_lanes": EX_LANES,
-        "fetch_every_sampled": 8,
-    }
-    rows["sampled_over_off"] = round(
-        rows["sampled"] / max(rows["off"], 1), 4
-    )
-    rows["criterion"] = "sampled >= 0.98x off (<= 2% overhead)"
-    return rows
-
-
 # ------------------------------------------------- containment overhead
 def run_fault_overhead(total_events: int, cpu: bool):
     """Failure-containment overhead config (ISSUE 4): the PR 3
@@ -2911,7 +2598,6 @@ CONFIGS = {
     "cep": (run_cep, 400_000),
     "cep_event_time": (run_cep_event_time, 400_000),
     "checkpoint_overhead": (run_checkpoint_overhead, 2_000_000),
-    "observability_overhead": (run_observability_overhead, 2_000_000),
     "ingest_pipeline": (run_ingest_pipeline, 4_000_000),
     "fault_overhead": (run_fault_overhead, 4_000_000),
     "device_update_ceiling": (run_device_update_ceiling, 2_000_000),

@@ -22,6 +22,18 @@ Design constraints:
   * BOUNDED. The ring holds `observability.trace-buffer-spans` records;
     old spans fall off — a perpetual job cannot grow host memory.
 
+Shared clock: the spans are on `time.perf_counter`, the profiler's trace
+(host annotations and device ops) on its own clock. While a tracer
+exists the executor calls `clock_anchor()` at sampled cycle boundaries,
+at most once a second: a `jax.profiler.TraceAnnotation("flink_tpu.clock")`
+around a `clock` span of the same ring. Under a running profiler session
+the annotation lands on the host plane, and the offset between its trace
+timestamp and the span's start maps every span onto the trace's clock;
+with no session it is one no-op TraceMe. Each span also records the name
+of the thread it ran on, and `watch_process()` adds a `gc` span per
+garbage collection and a `compile` span per XLA compile, the two usual
+causes of a whole-process stall.
+
 Compile visibility (`CompileEvents`): jax.monitoring emits an event per
 XLA backend compile (`/jax/core/compile/backend_compile_duration`). One
 process-wide listener counts them and records wall time, attributed to
@@ -31,14 +43,21 @@ recompile storm shows up as a named counter moving, not a mystery stall.
 
 from __future__ import annotations
 
+import gc
 import json
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
-# span record layout: (name, stage, t_start_s, dur_s, attrs_or_None)
-_Span = Tuple[str, str, float, float, Optional[dict]]
+# span record layout: (name, stage, t_start_s, dur_s, attrs_or_None,
+# thread_name); fields are only ever appended, never reordered
+_Span = Tuple[str, str, float, float, Optional[dict], str]
+
+# the profiler annotation wrapped around each `clock` span
+CLOCK_ANNOTATION = "flink_tpu.clock"
+# the most often a clock anchor is emitted, in perf_counter seconds
+CLOCK_ANCHOR_EVERY_S = 1.0
 
 # the step-loop phases the executor instruments; exported for tests and
 # the docs so the catalog cannot silently drift from the wiring
@@ -49,13 +68,23 @@ STEP_PHASES = (
                         #   at prep time, runtime/ingest.py)
     "stage",            # ingest-thread pad into the staging ring
     "transfer",         # ingest-thread H2D device_put + completion wait
-    "dispatch",         # device step dispatch (+ inflight-depth wait)
+    "dispatch",         # device step dispatch: the host enqueue alone
+    "inflight_wait",    # block on the oldest inflight step once more than
+                        #   pipeline.max-inflight-steps are queued
     "drain",            # resident ring-drain dispatch (pipeline.
                         #   resident-loop); attrs carry the slot count
     "fire",             # fire-step dispatch at a pane boundary
     "barrier_fetch",    # step-boundary scalar/lane fetch (the d2h barrier)
     "emit",             # fire extraction + sink invocation
+    "emit_fetch",       # inside emit: the [:n] slices + batched device_get
+    "emit_sink",        # inside emit: concatenation, key ids, result
+                        #   projection and the sink calls
     "checkpoint_sync",  # checkpoint sync phase (the only ckpt loop stall)
+    "poll",             # ingest side: source poll + encode of one batch
+    "handoff",          # ingest thread blocked on the full prefetch queue
+    "clock",            # clock anchor inside a flink_tpu.clock annotation
+    "gc",               # one garbage collection, any thread
+    "compile",          # one XLA compile, ending when its event arrived
 )
 
 
@@ -76,7 +105,9 @@ class SpanTracer:
         # counter-track samples ride their own ring so a chatty counter
         # cannot evict spans: (track, t_sample_s, {series: value})
         self._counters: deque = deque(maxlen=max(16, int(max_spans)))
-        self._lock = threading.Lock()
+        # reentrant: a gc callback may record while its thread is inside
+        # a record of its own
+        self._lock = threading.RLock()
         # perf_counter origin for relative span timestamps + the wall
         # clock at that origin so exported ts can be absolute-ish
         self.t0 = time.perf_counter()
@@ -84,6 +115,11 @@ class SpanTracer:
         self._cycle = -1
         self.active = False       # does the CURRENT cycle record spans?
         self.dropped = 0          # spans recorded while ring was full
+        self._last_anchor = float("-inf")
+        # wall clock (ns since the epoch, the profiler's absolute clock)
+        # at the tracer origin, as of the newest clock anchor
+        self.origin_trace_ns: Optional[int] = None
+        self._gc_t0: Optional[float] = None
 
     # -- recording (executor thread) ------------------------------------
     def begin_cycle(self) -> bool:
@@ -99,13 +135,54 @@ class SpanTracer:
         attribute read."""
         if t_end is None:
             t_end = time.perf_counter()
+        span = (name, stage or self.stage, t_start, t_end - t_start,
+                attrs or None, threading.current_thread().name)
         with self._lock:
             if len(self._spans) == self._spans.maxlen:
                 self.dropped += 1
-            self._spans.append((
-                name, stage or self.stage, t_start, t_end - t_start,
-                attrs or None,
-            ))
+            self._spans.append(span)
+
+    def clock_anchor(self):
+        """At a sampled cycle boundary, at most once per
+        CLOCK_ANCHOR_EVERY_S: a `clock` span inside a profiler annotation
+        named CLOCK_ANNOTATION (see the module docstring)."""
+        t_start = time.perf_counter()
+        if t_start - self._last_anchor < CLOCK_ANCHOR_EVERY_S:
+            return
+        self._last_anchor = t_start
+        self.origin_trace_ns = time.time_ns() - round(
+            (t_start - self.t0) * 1e9)
+        from jax.profiler import TraceAnnotation
+
+        with TraceAnnotation(CLOCK_ANNOTATION):
+            pass
+        self.rec("clock", t_start)
+
+    def watch_process(self):
+        """Record a `gc` span per garbage collection and a `compile` span
+        per XLA compile, until `unwatch_process()`."""
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+        CompileEvents.install()
+        CompileEvents.add_sink(self._on_compile)
+
+    def unwatch_process(self):
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+        CompileEvents.remove_sink(self._on_compile)
+
+    def _on_gc(self, phase: str, info: dict):
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+        elif self._gc_t0 is not None:
+            if self.active:
+                self.rec("gc", self._gc_t0, generation=info["generation"])
+            self._gc_t0 = None
+
+    def _on_compile(self, duration_s: float):
+        if self.active:
+            t_end = time.perf_counter()
+            self.rec("compile", t_end - duration_s, t_end)
 
     def rec_counter(self, track: str, t_sample: Optional[float] = None,
                     **values):
@@ -145,10 +222,15 @@ class SpanTracer:
 
     def to_chrome_trace(self) -> Dict[str, Any]:
         """Chrome-trace / Perfetto JSON object: complete ("ph": "X")
-        events with microsecond timestamps relative to the tracer origin.
-        Loadable directly in chrome://tracing and ui.perfetto.dev."""
+        events with microsecond timestamps relative to the tracer origin,
+        one `tid` per thread (``otherData.threads`` names them). Loadable
+        directly in chrome://tracing and ui.perfetto.dev. Once a clock
+        anchor was seen, ``otherData.origin_trace_ns`` is the origin on
+        the profiler's absolute clock, so ``origin_trace_ns + ts * 1e3``
+        overlays a span on a `jax.profiler` trace."""
         events = []
-        for name, stage, t_start, dur, attrs in self.snapshot():
+        tids: Dict[str, int] = {}
+        for name, stage, t_start, dur, attrs, thread in self.snapshot():
             ev = {
                 "name": name,
                 "cat": stage,
@@ -156,7 +238,7 @@ class SpanTracer:
                 "ts": round((t_start - self.t0) * 1e6, 3),
                 "dur": round(dur * 1e6, 3),
                 "pid": 1,
-                "tid": 1,
+                "tid": tids.setdefault(thread, len(tids) + 1),
             }
             if attrs:
                 ev["args"] = attrs
@@ -172,15 +254,19 @@ class SpanTracer:
                 "pid": 1,
                 "args": values,
             })
+        other = {
+            "stage": self.stage,
+            "sample_every": self.sample_every,
+            "origin_epoch_ms": round(self.epoch_ms, 1),
+            "spans_dropped": self.dropped,
+            "threads": {str(t): n for n, t in tids.items()},
+        }
+        if self.origin_trace_ns is not None:
+            other["origin_trace_ns"] = self.origin_trace_ns
         return {
             "traceEvents": events,
             "displayTimeUnit": "ms",
-            "otherData": {
-                "stage": self.stage,
-                "sample_every": self.sample_every,
-                "origin_epoch_ms": round(self.epoch_ms, 1),
-                "spans_dropped": self.dropped,
-            },
+            "otherData": other,
         }
 
     def dump(self, path: str) -> str:

@@ -1223,6 +1223,8 @@ class LocalExecutor:
             s.open()
         pipe.source.open()
         try:
+            if self._tracer is not None:
+                self._tracer.watch_process()
             from flink_tpu.datastream.window.assigners import (
                 CountWindowAssigner, GlobalWindows,
             )
@@ -1283,6 +1285,7 @@ class LocalExecutor:
                 CompileEvents.remove_sink(self._compile_sink)
                 self._compile_sink = None
             if self._tracer is not None:
+                self._tracer.unwatch_process()
                 dump = self.env.config.get_str(
                     "observability.trace-dump", ""
                 )
@@ -3954,6 +3957,29 @@ class LocalExecutor:
         inflight = deque()
         max_inflight = env.config.get_int("pipeline.max-inflight-steps", 4)
 
+        def _hold_inflight(act_handle):
+            """Queue a dispatched step's activity handle; past the depth
+            cap, block on the oldest. Returns when the block began, or
+            None when there was none."""
+            inflight.append(act_handle)
+            if len(inflight) <= max_inflight:
+                return None
+            t_w0 = time.perf_counter()
+            inflight.popleft().block_until_ready()
+            return t_w0
+
+        def _rec_dispatch(name, t0, t_wait, t1, batch, **attrs):
+            """A dispatch span (the host enqueue) and, where the inflight
+            depth blocked, the inflight_wait span that follows it."""
+            tracer.rec(name, t0, t1 if t_wait is None else t_wait,
+                       batch=batch, **attrs)
+            if t_wait is not None:
+                tracer.rec("inflight_wait", t_wait, t1, batch=batch)
+
+        def _batch_seqs(items):
+            """The poll sequence numbers of a dispatch group's batches."""
+            return [pb.seq for _, _, pb in items if pb is not None]
+
         # precomputed for the per-batch adaptive route choice
         _kg_ends = np.asarray(ctx.kg_bounds()[1])
 
@@ -3979,7 +4005,7 @@ class LocalExecutor:
             return (tier_mask_dev[0],) if use_tiers[0] else ()
 
         def run_update(hi, lo, ticks, values, valid, wm_ms, staged=None,
-                       route=None):
+                       route=None, batch=None):
             """Dispatch one update-only device step. No host sync: the
             result is not read, so transfers and compute of successive
             steps overlap (the round-1 loop blocked on every step). The
@@ -4057,15 +4083,13 @@ class LocalExecutor:
             # device pipeline is saturated -> the device-bound signal.
             # The depth-cap wait below is part of the same device-bound
             # attribution: it only takes time when the device lags.
-            inflight.append(act_handle)
-            if len(inflight) > max_inflight:
-                inflight.popleft().block_until_ready()
+            t_w0 = _hold_inflight(act_handle)
             t_d1 = time.perf_counter()
             phase_acc["dispatch"] += t_d1 - t_d0
             if t_r1 is not None:
-                tracer.rec("route", t_d0, t_r1, route=route)
-                tracer.rec("dispatch", t_r1, t_d1, route=route, tier=tier,
-                           step=metrics.steps)
+                tracer.rec("route", t_d0, t_r1, route=route, batch=batch)
+                _rec_dispatch("dispatch", t_r1, t_w0, t_d1, batch,
+                              route=route, tier=tier, step=metrics.steps)
             metrics.steps += 1
             if tier == "fast":
                 metrics.steps_fast += 1
@@ -4191,14 +4215,13 @@ class LocalExecutor:
                 state, (ovf_handle, act_handle, kgf_handle) = active(
                     state, *flat, wmv, *_tier_args(),
                 )
-            inflight.append(act_handle)
-            if len(inflight) > max_inflight:
-                inflight.popleft().block_until_ready()
+            t_w0 = _hold_inflight(act_handle)
             t_d1 = time.perf_counter()
             phase_acc["dispatch"] += t_d1 - t_d0
             if t_r1 is not None:
-                tracer.rec("dispatch", t_r1, t_d1, route=route, tier=tier,
-                           step=metrics.steps, k=k_fuse)
+                _rec_dispatch("dispatch", t_r1, t_w0, t_d1,
+                              _batch_seqs(items), route=route, tier=tier,
+                              step=metrics.steps, k=k_fuse)
             metrics.steps += k_fuse
             metrics.fused_dispatches += 1
             if tier == "fast":
@@ -4406,18 +4429,16 @@ class LocalExecutor:
                 fire_watch.append(
                     (fires, ovf_handle, time.perf_counter(), ds_h)
                 )
-                inflight.append(act_handle)
-                if len(inflight) > max_inflight:
-                    inflight.popleft().block_until_ready()
+                t_w0 = _hold_inflight(act_handle)
             finally:
                 if wd is not None:
                     wd.disarm(wd_prev)
             t_d1 = time.perf_counter()
             phase_acc["dispatch"] += t_d1 - t_d0
             if t_r1 is not None:
-                tracer.rec("drain", t_r1, t_d1, route=route, tier=tier,
-                           step=metrics.steps, slots=count,
-                           ring_depth=ring_depth)
+                _rec_dispatch("drain", t_r1, t_w0, t_d1, _batch_seqs(items),
+                              route=route, tier=tier, step=metrics.steps,
+                              slots=count, ring_depth=ring_depth)
             metrics.steps += count
             metrics.resident_drains += 1
             metrics.fused_fire_dispatches += 1
@@ -4476,12 +4497,14 @@ class LocalExecutor:
             elif staged_mode:
                 for args, wm_ms, _pb in items:
                     run_update(None, None, None, None, None, wm_ms,
-                               staged=args, route=route)
+                               staged=args, route=route,
+                               batch=None if _pb is None else _pb.seq)
                 if fuse_gauge[0] is not None:
                     fuse_gauge[0].set(1)
             else:
                 for args, wm_ms, _pb in items:
-                    run_update(*args, wm_ms, route=route)
+                    run_update(*args, wm_ms, route=route,
+                               batch=None if _pb is None else _pb.seq)
                 if fuse_gauge[0] is not None:
                     fuse_gauge[0].set(1)
             last_pb = items[-1][2]
@@ -5107,6 +5130,8 @@ class LocalExecutor:
                 for s in pipe.all_sinks:
                     s.invoke_reduced(n, vs)
                 return n
+            traced = tracer is not None and tracer.active
+            t_x0 = time.perf_counter() if traced else None
             slices, end_l = [], []
             # distinct due window ends (ticks). Spill contributions merge
             # into every fired value, but spill-ONLY keys append as new
@@ -5133,10 +5158,20 @@ class LocalExecutor:
             # one batched fetch: the lazy device slices transfer together
             # instead of 3 blocking round trips per (shard, lane)
             fetched = jax.device_get(slices)
+            t_x1 = time.perf_counter() if traced else None
+            n = _sink_fetched(cf, fetched, end_l, due_ends, appendable_ends)
+            if traced:
+                tracer.rec("emit_fetch", t_x0, t_x1, slices=len(slices))
+                tracer.rec("emit_sink", t_x1, fired=n)
+            return n
+
+        def _sink_fetched(cf, fetched, end_l, due_ends, appendable_ends):
+            """The host half of emit_fires: concatenate the fetched rows,
+            merge spill contributions, project results, call the sinks."""
             khi_l = [s[0] for s in fetched]
             klo_l = [s[1] for s in fetched]
             val_l = [s[2] for s in fetched]
-            if slices:
+            if fetched:
                 khi = np.concatenate(khi_l)
                 klo = np.concatenate(klo_l)
                 end_ms = np.concatenate(end_l)
@@ -5327,7 +5362,6 @@ class LocalExecutor:
             north-star metric; ref WindowOperator.onEventTime drain)."""
             if graph is not None:
                 return drain_chained(wm_ms, t_cross)
-            dbg = os.environ.get("FLINK_TPU_DRAIN_DEBUG")
             t_e0 = time.perf_counter()
             # pending resident-pipeline payloads predate this drain's
             # fires (and prune_stores below must not outrun them)
@@ -5338,10 +5372,6 @@ class LocalExecutor:
             # panes, so sampling here sees the live population the stall
             # is actually about
             refresh_kg_occupancy()
-            t_ovf = time.perf_counter()
-            if dbg:
-                print(f"[drain] ovf={1e3*(t_ovf-t_e0):.0f}ms",
-                      file=sys.stderr)
             total = 0
             F = win.fires_per_step
             # spill-tier presence is fixed for the whole drain
@@ -5380,10 +5410,6 @@ class LocalExecutor:
                     tracer.rec("fire", t_f0, t_fd, reduced=use_reduced)
                     tracer.rec("barrier_fetch", t_fd, t_f1)
                     tracer.rec("emit", t_f1, t_em, fired=n_emit)
-                if dbg:
-                    print(f"[drain] fire+lanes={1e3*(t_f1-t_f0):.0f}ms "
-                          f"emit={1e3*(time.perf_counter()-t_f1):.0f}ms "
-                          f"n={n_emit}", file=sys.stderr)
                 total += n_emit
                 if t_cross is not None:
                     # weight by WINDOWS fired (metrics.fires delta), not by
@@ -5872,9 +5898,10 @@ class LocalExecutor:
                     deferred = True
             elif pb.staged is not None:
                 run_update(None, None, None, None, None, wm_ms,
-                           staged=pb.staged, route=pb.route)
+                           staged=pb.staged, route=pb.route, batch=pb.seq)
             else:
-                run_update(*_pad_planned(pb), wm_ms, route=pb.route)
+                run_update(*_pad_planned(pb), wm_ms, route=pb.route,
+                           batch=pb.seq)
             if fire_now and not in_scan:
                 drain_fires(wm_ms, time.perf_counter())
                 host_fired_pane = wp
@@ -5913,8 +5940,10 @@ class LocalExecutor:
             if runtime_ctl[0] is not None and td is not None \
                     and state is not None:
                 runtime_ctl[0].service()
-            if tracer is not None:
-                tracer.begin_cycle()   # sampling decision for this cycle
+            # sampling decision for this cycle; a sampled one may anchor
+            # the spans to the profiler's clock
+            if tracer is not None and tracer.begin_cycle():
+                tracer.clock_anchor()
             t_c0 = time.perf_counter()
             phase_acc["dispatch"] = phase_acc["emit"] = 0.0
             if pending_batch[0] is not None:
@@ -6184,7 +6213,7 @@ class LocalExecutor:
                             "mask", [(c_args, wm_chunk, None)]
                         )
                     else:
-                        run_update(*chunk, wm_chunk)
+                        run_update(*chunk, wm_chunk, batch=pb.seq)
                 # catch-up slices must fire between groups or newer
                 # panes would evict older unfired ones from the ring
                 if catch_up:

@@ -133,6 +133,9 @@ class PreppedBatch:
     # staged fresh (the shard-local backpressure seam); the consumer
     # releases per shard at the drain boundary (release_shards)
     ring_seqs: Optional[list] = None
+    # poll sequence number, given at the poll: the `batch` attribute of
+    # every span this batch passes through
+    seq: int = -1
 
 
 @dataclasses.dataclass
@@ -349,7 +352,7 @@ class StagingRing:
         return buf
 
     def stage(self, plan: IngestPlan, hi, lo, ticks, values, n: int,
-              route: str, tracer=None) -> Tuple:
+              route: str, tracer=None, batch: int = -1) -> Tuple:
         """Pad into the next ring slot and device_put with the route's
         sharding; returns committed (hi, lo, ticks, values, valid)."""
         if self._reuse:
@@ -375,8 +378,8 @@ class StagingRing:
         # loop receives arrays it can dispatch without ever waiting
         jax.block_until_ready(staged)  # host-sync-ok: ingest-thread transfer completion, off the step loop
         if tracer is not None and tracer.active:
-            tracer.rec("stage", t0, t_pad, n=n)
-            tracer.rec("transfer", t_pad, route=route)
+            tracer.rec("stage", t0, t_pad, n=n, batch=batch)
+            tracer.rec("transfer", t_pad, route=route, batch=batch)
         return staged
 
 
@@ -476,8 +479,8 @@ class DeviceBatchRing:
 
     # -- producer (prefetch thread) --------------------------------------
     def try_publish(self, plan: IngestPlan, hi, lo, ticks, values,
-                    n: int, route: str, epoch: int,
-                    tracer=None) -> Optional[Tuple[int, Tuple]]:
+                    n: int, route: str, epoch: int, tracer=None,
+                    batch: int = -1) -> Optional[Tuple[int, Tuple]]:
         """Stage one batch into the next ring slot; returns (seq,
         staged) or None when the ring is full. The stage itself blocks
         for transfer completion on THIS thread (StagingRing.stage), so a
@@ -491,7 +494,7 @@ class DeviceBatchRing:
                 return None
             seq = self._write
         staged = self._staging.stage(plan, hi, lo, ticks, values, n,
-                                     route, tracer=tracer)
+                                     route, tracer=tracer, batch=batch)
         max_tick = int(ticks[:n].max()) if n else None
         with self._lock:
             self._slots[seq % self.depth] = (seq, epoch, staged)
@@ -671,7 +674,7 @@ class ShardedDeviceBatchRing:
     # -- producer (prefetch thread) --------------------------------------
     def publish_batch(self, plan: IngestPlan, hi, lo, ticks, values,
                       shard: np.ndarray, n: int, epoch: int,
-                      tracer=None) -> Tuple[list, Tuple]:
+                      tracer=None, batch: int = -1) -> Tuple[list, Tuple]:
         """Partition one planned batch by owning shard and publish each
         slice into that shard's ring lane. Returns ``(ring_seqs,
         staged)``: per-shard slot sequences (None where that lane was
@@ -741,8 +744,8 @@ class ShardedDeviceBatchRing:
                     np.fromiter(self._write, np.int32, self.n_shards),
                     self._cursor_sharding)
         if tracer is not None and tracer.active:
-            tracer.rec("stage", t0, t_pad, n=n)
-            tracer.rec("transfer", t_pad, route="sharded")
+            tracer.rec("stage", t0, t_pad, n=n, batch=batch)
+            tracer.rec("transfer", t_pad, route="sharded", batch=batch)
         return seqs, staged
 
     # -- consumer (step loop) --------------------------------------------
@@ -929,6 +932,7 @@ class IngestPipeline:
         # epoch the live thread was spawned under: a DEAD thread is only
         # respawned after a restore bumped the epoch (see _ensure_thread)
         self._thread_epoch = -1
+        self._seq = 0            # poll sequence number of the next batch
 
     # -- plan ------------------------------------------------------------
     @property
@@ -998,7 +1002,8 @@ class IngestPipeline:
             pb.route = plan_route(plan, pb.hi, pb.lo)
         tracer = self.tracer
         if tracer is not None and tracer.active:
-            tracer.rec("route", t_r0, route=pb.route, planned=True)
+            tracer.rec("route", t_r0, route=pb.route, planned=True,
+                       batch=pb.seq)
         if self._ring is not None:
             pub = None
             if shard_of is not None:
@@ -1007,13 +1012,13 @@ class IngestPipeline:
                 # costs its own shard a fresh row)
                 pb.ring_seqs, pb.staged = dr.publish_batch(
                     plan, pb.hi, pb.lo, ticks, values, shard_of, pb.n,
-                    pb.epoch, tracer=tracer,
+                    pb.epoch, tracer=tracer, batch=pb.seq,
                 )
                 pub = (None, pb.staged)
             elif dr is not None and not dr.sharded:
                 pub = dr.try_publish(
                     plan, pb.hi, pb.lo, ticks, values, pb.n, pb.route,
-                    pb.epoch, tracer=tracer,
+                    pb.epoch, tracer=tracer, batch=pb.seq,
                 )
                 if pub is not None:
                     pb.ring_seq, pb.staged = pub
@@ -1025,7 +1030,7 @@ class IngestPipeline:
                 # residency without ever blocking the source poll
                 pb.staged = self._ring.stage(
                     plan, pb.hi, pb.lo, ticks, values, pb.n, pb.route,
-                    tracer=tracer,
+                    tracer=tracer, batch=pb.seq,
                 )
             # the ring slot owns the padded copies; drop the host arrays
             # so nothing can alias a recycled slot
@@ -1051,8 +1056,7 @@ class IngestPipeline:
             epoch = self._epoch
             park_after = False
             try:
-                with self.source_lock:
-                    pb = self.prep_fn()
+                pb = self._poll()
                 pb.epoch = epoch
                 self._finish(pb)
                 item = ("ok", epoch, pb)
@@ -1071,18 +1075,42 @@ class IngestPipeline:
             self._put(item)
         self._parked.set()
 
+    def _poll(self) -> PreppedBatch:
+        """One prep (source poll + encode), numbered in poll order."""
+        with self.source_lock:
+            t0 = time.perf_counter()
+            pb = self.prep_fn()
+            pb.seq = self._seq
+            self._seq += 1
+        tracer = self.tracer
+        if tracer is not None and tracer.active:
+            tracer.rec("poll", t0, batch=pb.seq)
+        return pb
+
     def _put(self, item):
-        while not self._stop.is_set():
-            if self._pause_req.is_set():
-                # consumer is pausing: the epoch is being invalidated and
-                # the consumer would skip this item anyway — drop rather
-                # than deadlock on a full queue while pause() waits
-                return
-            try:
-                self._q.put(item, timeout=0.05)
-                return
-            except queue.Full:
-                continue
+        t_full = None            # when the queue was first found full
+        try:
+            while not self._stop.is_set():
+                if self._pause_req.is_set():
+                    # consumer is pausing: the epoch is being invalidated
+                    # and the consumer would skip this item anyway — drop
+                    # rather than deadlock on a full queue while pause()
+                    # waits
+                    return
+                try:
+                    if t_full is None:
+                        self._q.put(item, block=False)
+                    else:
+                        self._q.put(item, timeout=0.05)
+                    return
+                except queue.Full:
+                    if t_full is None:
+                        t_full = time.perf_counter()
+        finally:
+            tracer = self.tracer
+            if t_full is not None and tracer is not None and tracer.active:
+                tracer.rec("handoff", t_full,
+                           batch=item[2].seq if item[0] == "ok" else None)
 
     def _ensure_thread(self):
         if self._thread is not None and not self._thread.is_alive():
@@ -1110,8 +1138,7 @@ class IngestPipeline:
     # -- consumer --------------------------------------------------------
     def next(self) -> PreppedBatch:
         if not self.prefetch:
-            with self.source_lock:
-                pb = self.prep_fn()
+            pb = self._poll()
             pb.epoch = self._epoch
             return self._finish(pb)
         self._ensure_thread()

@@ -10,7 +10,10 @@ phase appears as a span), the web surface (/traces, /keygroups,
 /metrics), and the JSON-404 guards on job-scoped endpoints.
 """
 
+import gc
+import glob
 import json
+import os
 import urllib.error
 import urllib.request
 
@@ -20,8 +23,13 @@ import pytest
 from flink_tpu import StreamExecutionEnvironment
 from flink_tpu.core.config import Configuration
 from flink_tpu.core.time import TimeCharacteristic
-from flink_tpu.metrics.tracing import CompileEvents, SpanTracer
-from flink_tpu.runtime.sinks import CountingSink
+from flink_tpu.metrics.tracing import (
+    CLOCK_ANNOTATION,
+    STEP_PHASES,
+    CompileEvents,
+    SpanTracer,
+)
+from flink_tpu.runtime.sinks import CountingSink, DiscardingSink
 from flink_tpu.runtime.sources import GeneratorSource
 
 
@@ -106,7 +114,9 @@ def test_span_context_manager_respects_active():
 
 # ------------------------------------------------- executor wiring (e2e)
 
-def _windowed_env(extra_cfg=None, total=20_000):
+def _windowed_env(extra_cfg=None, total=20_000, sink=None, on_poll=None):
+    """A traced keyed tumbling-sum job; `on_poll(offset)` runs inside
+    every source poll, on the thread that polls."""
     env = StreamExecutionEnvironment(Configuration({
         "observability.tracing": True,
         "observability.kg-stats-interval-ms": 0,
@@ -119,10 +129,12 @@ def _windowed_env(extra_cfg=None, total=20_000):
     env.batch_size = 1024
 
     def gen(offset, n):
+        if on_poll is not None:
+            on_poll(offset)
         idx = np.arange(offset, offset + n, dtype=np.int64)
         return {"key": idx % 100, "value": np.ones(n, np.float32)}, idx // 10
 
-    sink = CountingSink()
+    sink = CountingSink() if sink is None else sink
     (
         env.add_source(GeneratorSource(gen, total=total))
         .key_by(lambda c: c["key"])
@@ -166,16 +178,157 @@ def test_windowed_job_records_step_phase_spans():
 
 
 def test_tracing_off_by_default_and_sampling():
+    callbacks = list(gc.callbacks)
     env, _ = _windowed_env({"observability.tracing": False})
     env.execute("untraced")
     assert env._span_tracer is None
+    # off: no gc hook was ever registered, nor one left behind
+    assert gc.callbacks == callbacks
 
     env2, _ = _windowed_env({"observability.trace-sample-every": 1000})
     env2.execute("sampled")
-    # cycle 0 is sampled, later cycles are not: far fewer spans than steps
-    spans = len(env2._span_tracer)
+    # the traced job's gc hook went with the job
+    assert gc.callbacks == callbacks
+    # cycle 0 is sampled, later cycles are not: far fewer step spans than
+    # steps (cycle 0 is also the job's set-up, whose compiles and
+    # collections are process-wide records, not step spans)
+    spans = sum(1 for s in env2._span_tracer.snapshot()
+                if s[0] not in ("compile", "gc"))
     steps = env2.last_job.metrics.steps
     assert 0 < spans < steps + 10
+
+
+def _by_name(tr):
+    out = {}
+    for s in tr.snapshot():
+        out.setdefault(s[0], []).append(s)
+    return out
+
+
+def test_dispatch_and_inflight_wait_share_the_poll_batch_id():
+    """`dispatch` is the host enqueue alone; the block on the oldest
+    inflight step is its own `inflight_wait` span right after it, and
+    both carry the `batch` id of that batch's `poll`, which ran on the
+    ingest thread."""
+    env, sink = _windowed_env({"pipeline.max-inflight-steps": 1})
+    env.execute("inflight")
+    assert sink.value_sum == 20_000
+    spans = _by_name(env._span_tracer)
+    assert set(spans) <= set(STEP_PHASES) | {"kg_occupancy"}
+    polls = {s[4]["batch"]: s for s in spans["poll"]}
+    assert sorted(polls) == list(range(len(polls)))
+    waits = {s[4]["batch"]: s for s in spans["inflight_wait"]}
+    paired = 0
+    for d in spans["dispatch"]:
+        b = d[4]["batch"]
+        if b is None or b not in waits:
+            continue
+        w = waits[b]
+        # the wait starts where the enqueue ended
+        assert w[2] == pytest.approx(d[2] + d[3], abs=1e-9)
+        assert d[5] == w[5] != polls[b][5]
+        assert polls[b][5] == "flink-tpu-ingest"
+        assert d[2] >= polls[b][2] + polls[b][3]
+        paired += 1
+    assert paired >= 5
+
+
+@pytest.mark.parametrize("path", ["drain_fires", "consume_fires"])
+def test_emit_fetch_and_sink_lie_inside_emit(path):
+    extra = {} if path == "drain_fires" else {
+        "pipeline.prefetch": "on",
+        "pipeline.device-staging": "on",
+        "pipeline.resident-loop": "on",
+    }
+    env, _ = _windowed_env(extra, sink=DiscardingSink())
+    env.execute(f"emit-{path}")
+    spans = _by_name(env._span_tracer)
+    emits = [(s[2], s[2] + s[3]) for s in spans["emit"]]
+    for child in ("emit_fetch", "emit_sink"):
+        assert spans[child]
+        for s in spans[child]:
+            assert any(a <= s[2] and s[2] + s[3] <= b for a, b in emits)
+    assert sum(s[4]["fired"] for s in spans["emit_sink"]) > 0
+
+
+def _collect(offset):
+    gc.collect()
+
+
+def _compile_new_shape(offset):
+    import jax
+    import jax.numpy as jnp
+
+    # a fresh shape per poll: one XLA compile each
+    jax.jit(lambda x: x + 1)(jnp.zeros(offset // 1024 + 1)).block_until_ready()
+
+
+@pytest.mark.parametrize("name,on_poll", [("gc", _collect),
+                                          ("compile", _compile_new_shape)])
+def test_process_wide_stall_spans(name, on_poll):
+    """A collection or a compile during a traced job is a span of its own,
+    on the thread that paid for it (here the ingest thread's poll)."""
+    env, _ = _windowed_env(on_poll=on_poll, total=8192)
+    env.execute(f"stall-{name}")
+    spans = _by_name(env._span_tracer)
+    mine = [s for s in spans[name] if s[5] == "flink-tpu-ingest"]
+    assert mine and all(s[3] > 0 for s in mine)
+    if name == "gc":
+        assert any(s[4]["generation"] == 2 for s in mine)
+    polls = [(s[2], s[2] + s[3]) for s in spans["poll"]]
+    assert any(a <= s[2] and s[2] + s[3] <= b + 1e-6
+               for s in mine for a, b in polls)
+
+
+def test_clock_anchor_maps_onto_the_profiler_trace(tmp_path):
+    """Each `clock` span sits inside a flink_tpu.clock annotation of the
+    profiler trace; `origin_trace_ns` puts it on the trace's absolute
+    clock within 1 ms, and anchors come at most once a second."""
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = SpanTracer()
+    tr.begin_cycle()
+    with jax.profiler.trace(str(tmp_path)):
+        tr.clock_anchor()
+        tr.clock_anchor()             # within the second: no anchor
+        tr._last_anchor = float("-inf")
+        tr.clock_anchor()
+    clocks = [s for s in tr.snapshot() if s[0] == "clock"]
+    assert len(clocks) == 2
+    path, = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    start = [v for pl in pd.planes for k, v in pl.stats
+             if k == "profile_start_time"][0]
+    anns = sorted(ev.start_ns for pl in pd.planes for ln in pl.lines
+                  for ev in ln.events if ev.name == CLOCK_ANNOTATION)
+    assert len(anns) == 2
+    origin = tr.to_chrome_trace()["otherData"]["origin_trace_ns"]
+    for s, ann in zip(clocks, anns):
+        mapped = origin + (s[2] - tr.t0) * 1e9
+        assert abs(start + ann - mapped) < 1e6
+    # the offset between span and annotation holds from anchor to anchor
+    offs = [ann - s[2] * 1e9 for s, ann in zip(clocks, anns)]
+    assert abs(offs[0] - offs[1]) < 1e6
+
+
+def test_chrome_trace_gives_each_thread_its_track():
+    import threading
+
+    tr = SpanTracer()
+    tr.begin_cycle()
+    tr.rec("dispatch", 1.0, 1.1)
+    t = threading.Thread(target=tr.rec, args=("poll", 1.0, 1.2),
+                         name="ingest-x")
+    t.start()
+    t.join()
+    ct = tr.to_chrome_trace()
+    tids = {ev["name"]: ev["tid"] for ev in ct["traceEvents"]}
+    assert tids["dispatch"] != tids["poll"]
+    assert ct["otherData"]["threads"][str(tids["poll"])] == "ingest-x"
+    # no anchor yet: nothing to overlay on a profiler trace
+    assert "origin_trace_ns" not in ct["otherData"]
 
 
 def test_kg_stats_gating():
