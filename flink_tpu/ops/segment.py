@@ -112,6 +112,41 @@ def segment_sort(seg_ids, valid):
     return order, ids_s, valid_s, seg_start, rep_mask
 
 
+def stable_partition(mask, *columns):
+    """Stream compaction: move the rows where ``mask`` holds to the front,
+    in input order, and zero every row past them.
+
+    mask:    bool [N]
+    columns: arrays with leading dimension N
+
+    Returns ``(count, *packed)``: ``count`` is the int32 number of True
+    rows, and ``packed[c][i] == columns[c][flatnonzero(mask)[i]]`` for
+    ``i < count``, 0 beyond. The fire pack (window_kernels.
+    ``_pack_fire_lanes``) compacts every fire lane through this.
+
+    One stable ``lax.sort`` keyed on the inverted mask (0 = keep, 1 =
+    drop), so there is no data-dependent loop. 1-D columns ride the sort
+    as payload operands; wider columns ride as a row index and take one
+    row gather (sort operands must share one shape). The cumsum +
+    ``searchsorted`` form this replaces lowers to a scan of
+    ``ceil(log2(N + 1))`` full-length gathers: 21 of them at N = 1M,
+    about 957 ms per 4-lane fire on a TPU v5e.
+    """
+    n = mask.shape[0]
+    flat = [c for c in columns if c.ndim == 1]
+    wide = len(flat) < len(columns)
+    rows = [jnp.arange(n, dtype=jnp.int32)] if wide else []
+    out = jax.lax.sort(((~mask).astype(jnp.int32), *flat, *rows),
+                       num_keys=1, is_stable=True)
+    flat_s = iter(out[1:1 + len(flat)])
+    moved = [next(flat_s) if c.ndim == 1 else c[out[-1]] for c in columns]
+    count = jnp.sum(mask, dtype=jnp.int32)
+    keep = jnp.arange(n, dtype=jnp.int32) < count
+    return (count,) + tuple(
+        jnp.where(_bshape(keep, m), m, jnp.zeros((), m.dtype)) for m in moved
+    )
+
+
 def reduce_sorted(order, valid_s, seg_start, values, combine: Callable,
                   neutral):
     """Gather a pytree of per-lane columns through a ``segment_sort``

@@ -43,6 +43,7 @@ from flink_tpu.ops.segment import (
     scatter_combine,
     segment_sort,
     sort_values,
+    stable_partition,
 )
 
 # np scalar, not jnp: a module-level jnp call would initialize the JAX
@@ -1011,6 +1012,9 @@ class CompactFires:
     whole lane shares window_end_ticks[f]. The host reads the small fields
     (counts/lane_valid/window_end/n_fires), then slices only [:counts[f]]
     of the packed arrays — O(actual fires) transferred instead of O(F*C).
+    Entries j >= counts[f] are zero. Built by ``_pack_fire_lanes``: one
+    stable sort per lane keyed on the inverted emit mask, so the entries
+    keep slot order.
     """
 
     key_hi: jax.Array           # uint32 [Ft, C]
@@ -1043,10 +1047,11 @@ class ReducedFires:
     """Fire output reduced ON DEVICE to per-lane scalars — the drain path
     for device_reduce-capable sinks (runtime/sinks.py). Nothing O(C) is
     packed or transferred: the host reads five [Ft]-sized fields and the
-    drain is done. Compared to CompactFires this skips the 3 full-capacity
-    pack scatters per lane that dominate the fire step's cost (the
-    reference's timer drain materializes every (key, window, value) triple;
-    a counting/aggregating sink never needs them —
+    drain is done. Compared to CompactFires this skips the per-lane
+    compaction (a stable sort of [C] keys with the key and value columns
+    as payload, ``_pack_fire_lanes``) and the [Ft, C] payload planes (the
+    reference's timer drain materializes every (key, window, value)
+    triple; a counting/aggregating sink never needs them —
     ref WindowOperator.java:222 emit path).
     """
 
@@ -1083,28 +1088,22 @@ def _pack_fire_lanes(table: SlotTable, mask, values):
     fused-fire resident advance (the gated in-scan pack) so the payload
     bytes cannot diverge between the split and resident drains.
 
-    Round 7: the stream compaction is GATHER-formulated — cumsum the
-    mask, then ``searchsorted`` finds output position i's source lane
-    (the first lane whose running count reaches i+1; a vectorized
-    binary search, NOT a sort) and three gathers move the payload.
-    The previous three row SCATTERS per lane serialized on XLA CPU
-    (~60ns/element — the single biggest term of the firing-stream
-    ceiling); the gather form is ~8x cheaper there and collision-free
-    everywhere, with bit-identical output."""
-    C = table.capacity
+    The stream compaction is one stable sort per lane
+    (``segment.stable_partition``): keyed on the inverted mask, with the
+    two key columns and a scalar value column riding as payload, so the
+    emitted slots come out first and in slot order, and rows at
+    ``count`` and beyond are zeroed. A vector value rides as a row index
+    and takes one row gather. There is no data-dependent loop. The
+    cumsum + ``searchsorted`` form this replaces was a 21-step scan
+    (``ceil(log2(C + 1))`` at C = 1M) of full-length gathers over all
+    lanes: about 957 ms per 4-lane fire on a TPU v5e, for output
+    bit-identical to this one."""
     tk = table.keys
-    ar = jnp.arange(C, dtype=jnp.int32)
 
     def pack(mask_f, vals_f):
-        cs = jnp.cumsum(mask_f.astype(jnp.int32))
-        count = cs[-1]
-        sel = jnp.searchsorted(cs, ar + 1, side="left")
-        ok = ar < count
-        selc = jnp.minimum(sel, jnp.int32(C - 1))
-        khi = jnp.where(ok, tk[selc, 0], jnp.uint32(0))
-        klo = jnp.where(ok, tk[selc, 1], jnp.uint32(0))
-        v = jnp.where(_expand(ok, vals_f), vals_f[selc],
-                      jnp.zeros((), vals_f.dtype))
+        count, khi, klo, v = stable_partition(
+            mask_f, tk[:, 0], tk[:, 1], vals_f
+        )
         vsum = jnp.sum(
             jnp.where(_expand(mask_f, vals_f), vals_f, 0.0)
         ).astype(jnp.float32)
@@ -1116,8 +1115,10 @@ def _pack_fire_lanes(table: SlotTable, mask, values):
 def compact_fires(table: SlotTable, fr: FireResult) -> CompactFires:
     """Pack a dense FireResult into per-lane prefix buffers on device.
 
-    Delegates the compaction to ``_pack_fire_lanes`` (cumsum +
-    searchsorted + gathers — see there). Replaces the host-side
+    Delegates the compaction to ``_pack_fire_lanes`` (one stable sort
+    per lane keyed on the inverted emit mask, payload riding the sort;
+    no data-dependent loop — see there for why the earlier cumsum +
+    searchsorted form was slow on TPU). Replaces the host-side
     np.nonzero sweep over [Ft, C] masks and the full table.keys transfer
     the round-1 emit path paid every step.
     """

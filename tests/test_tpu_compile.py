@@ -21,6 +21,7 @@ from flink_tpu.parallel.exchange import bucket_capacity
 from flink_tpu.parallel.mesh import SHARD_AXIS, MeshContext
 from flink_tpu.runtime.step import (
     WindowStageSpec,
+    build_window_fire_step,
     build_window_resident_drain,
     build_window_sharded_drain,
     build_window_update_step,
@@ -100,6 +101,8 @@ def _lowered(topo):
     return {
         "update": build_window_update_step(one, spec).lower(
             state1, *_batch(one, BATCH), *_scalars(one, (1,))),
+        "fire": build_window_fire_step(one, spec).lower(
+            state1, *_scalars(one, (1,))),
         "resident": build_window_resident_drain(one, spec, RING_DEPTH).lower(
             state1, *_batch(one, BATCH) * RING_DEPTH,
             *_scalars(one, (1, RING_DEPTH), ())),              # wmv, count
@@ -125,7 +128,8 @@ def compiled(topo):
                for name, low in lowered.items()}
 
 
-@pytest.mark.parametrize("name", ["update", "resident", "while", "sharded"])
+@pytest.mark.parametrize(
+    "name", ["update", "fire", "resident", "while", "sharded"])
 def test_main_path_kernel_compiles_for_v5e(compiled, name):
     program = compiled[name].result()
     mem = program.memory_analysis()

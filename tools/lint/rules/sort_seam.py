@@ -15,7 +15,8 @@ This rule fails the build when a sort primitive (``jnp.sort`` /
 ``jax.lax.sort_key_val``, under any of the conventional module aliases)
 appears in ``flink_tpu/ops`` outside ``segment.py``. Kernels order
 lanes through the segment.py wrappers instead (``segment_sort``,
-``sort_values``, ``argsort_ids``, ``invert_permutation``), which keeps
+``sort_values``, ``argsort_ids``, ``invert_permutation``,
+``stable_partition``), which keeps
 every sort call site greppable in one file and the one-sort-per-batch
 contract reviewable at the seam.
 
