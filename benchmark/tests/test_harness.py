@@ -48,7 +48,8 @@ def test_cell_metrics_follow_the_entries():
                                                       "setup_s"}
     assert names("tumble_sum_1m.rate80", True) == {
         "job.result_latency_p99_ms.rate", "ingest.source_lag_ms.rate",
-        "executor.fire_latency_p99_ms.rate", "device.idle_share.rate"}
+        "executor.fire_latency_p99_ms.rate", "device.idle_share.rate",
+        "ingest.queue_wait_p99_ms.rate", "job.gc_pause_max_ms.rate"}
     assert "update_roofline" in names("tumble_sum_1m.saturate", True)
 
 
